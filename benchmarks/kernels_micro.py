@@ -28,11 +28,13 @@ def decode_rows() -> list:
     # so the paged pool holds one 1024-token sequence, not two
     page = 64
     n_ptab = 1024 // page
+    # pool pages are (Hkv, page, D)
     kp = jnp.concatenate(
-        [jnp.zeros((1, page, 2, 64)),                # physical page 0: trash
-         kd[0].reshape(n_ptab, page, 2, 64)])
+        [jnp.zeros((1, 2, page, 64)),                # physical page 0: trash
+         kd[0].reshape(n_ptab, page, 2, 64).swapaxes(1, 2)])
     vp = jnp.concatenate(
-        [jnp.zeros((1, page, 2, 64)), vd[0].reshape(n_ptab, page, 2, 64)])
+        [jnp.zeros((1, 2, page, 64)),
+         vd[0].reshape(n_ptab, page, 2, 64).swapaxes(1, 2)])
     ptab = jnp.tile(jnp.arange(1, n_ptab + 1), (2, 1))
     outp, us = timed(lambda: fd.paged_flash_decode(
         qd, kp, vp, ptab, kl).block_until_ready(), repeat=3)
@@ -127,17 +129,19 @@ def sharded_paged_rows(mesh, tp: int) -> list:
     vd = jax.random.normal(KEY, (B, S, Hkv, D))
     kl = jnp.array([700, 1000])
     kp = jnp.concatenate(
-        [jnp.zeros((1, page, Hkv, D)), kd[0].reshape(n_ptab, page, Hkv, D)])
+        [jnp.zeros((1, Hkv, page, D)),
+         kd[0].reshape(n_ptab, page, Hkv, D).swapaxes(1, 2)])
     vp = jnp.concatenate(
-        [jnp.zeros((1, page, Hkv, D)), vd[0].reshape(n_ptab, page, Hkv, D)])
+        [jnp.zeros((1, Hkv, page, D)),
+         vd[0].reshape(n_ptab, page, Hkv, D).swapaxes(1, 2)])
     ptab = jnp.tile(jnp.arange(1, n_ptab + 1), (2, 1))
 
     # the unfused path the sharded engine falls back to: gather the pool
     # into contiguous K/V copies, then contiguous flash-decode
     @jax.jit
     def unfused(q, kpool, vpool, pt, lens):
-        kc = kpool[pt].reshape(B, -1, Hkv, D)
-        vc = vpool[pt].reshape(B, -1, Hkv, D)
+        kc = kpool[pt].swapaxes(2, 3).reshape(B, -1, Hkv, D)
+        vc = vpool[pt].swapaxes(2, 3).reshape(B, -1, Hkv, D)
         return fd.flash_decode(q, kc, vc, lens)
 
     out_u, us_u = timed(lambda: unfused(qd, kp, vp, ptab,
@@ -145,8 +149,8 @@ def sharded_paged_rows(mesh, tp: int) -> list:
     rows.append((f"kernel/unfused_paged_decode_tp{tp}", us_u,
                  f"B{B} S{S} H{H}/{Hkv} D{D} page{page} gather"))
 
-    kp_sh = jax.device_put(kp, NamedSharding(mesh, P(None, None, "model")))
-    vp_sh = jax.device_put(vp, NamedSharding(mesh, P(None, None, "model")))
+    kp_sh = jax.device_put(kp, NamedSharding(mesh, P(None, "model")))
+    vp_sh = jax.device_put(vp, NamedSharding(mesh, P(None, "model")))
     out_f, us_f = timed(lambda: fd.sharded_paged_flash_decode(
         qd, kp_sh, vp_sh, ptab, kl, mesh).block_until_ready(), repeat=3)
     rows.append((f"kernel/fused_paged_decode_shardmap_tp{tp}", us_f,

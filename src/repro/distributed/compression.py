@@ -16,6 +16,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -38,8 +39,6 @@ def compressed_allreduce(grads: Any, mesh, axis_name: str = "data",
     grads leaves: (n_replicas, ...) sharded over ``axis_name``.
     Returns (mean_grads (…), new_error_state (n_replicas, ...)).
     """
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis_name]
     if error_state is None:
         error_state = jax.tree.map(lambda g: jnp.zeros(g.shape, jnp.float32),
@@ -62,7 +61,7 @@ def compressed_allreduce(grads: Any, mesh, axis_name: str = "data",
                       P(axis_name, *([None] * (nd - 1)))),
             out_specs=(P(*([None] * (nd - 1))),
                        P(axis_name, *([None] * (nd - 1)))),
-            check_rep=False,
+            check_vma=False,
         )(g_stack, e_stack)
 
     flat_g, tdef = jax.tree_util.tree_flatten(grads)

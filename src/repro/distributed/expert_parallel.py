@@ -15,16 +15,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.moe_gmm.ops import moe_gmm
 from repro.models.layers import moe_gates
-
-try:                                      # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-except ImportError:                       # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _round_up(n: int, m: int) -> int:
@@ -71,7 +67,7 @@ def ep_moe_mix(p, cfg, x: jax.Array, mesh: Mesh,
         return jax.lax.psum(out, axis)
 
     in_specs = (P(), P(None, None, axis), P(axis), P(axis), P(axis))
-    # check_rep=False: pallas_call has no replication rule; the psum above
+    # check_vma=False: pallas_call has no replication rule; the psum above
     # makes the output replicated by construction
-    return _shard_map(local_mix, mesh=mesh, in_specs=in_specs,
-                      out_specs=P(), check_rep=False)(x, gates, wg, wu, wd)
+    return shard_map(local_mix, mesh=mesh, in_specs=in_specs,
+                     out_specs=P(), check_vma=False)(x, gates, wg, wu, wd)

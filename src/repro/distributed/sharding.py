@@ -378,7 +378,7 @@ def cache_pspecs(cfg: ModelConfig, pol: ShardingPolicy, cache_sds) -> Any:
 
 def paged_cache_pspecs(cfg: ModelConfig, pol: ShardingPolicy,
                        cache_sds) -> Any:
-    """Paged KV pool: (L, n_pages, page_size, H, D) shards KV **heads** on
+    """Paged KV pool: (L, n_pages, H, page_size, D) shards KV **heads** on
     the tp axis — page indices are request-local and must stay addressable
     from every shard, so the page axis replicates and the head axis (which
     TP attention already splits) carries the partition.  MLA's latent pool
@@ -389,7 +389,7 @@ def paged_cache_pspecs(cfg: ModelConfig, pol: ShardingPolicy,
         path = _path_names(kp)
         name = path[-1]
         if name in ("kp", "vp"):
-            spec = (None, None, None, tp, None)
+            spec = (None, None, tp, None, None)
         else:                               # ckvp + anything unforeseen
             spec = tuple([None] * len(leaf.shape))
         return _sanitize(pol.mesh, leaf.shape, spec, path=".".join(path))

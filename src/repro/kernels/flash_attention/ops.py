@@ -7,6 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
@@ -18,8 +19,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """GQA flash attention. q: (B, Sq, H, D); k, v: (B, Sk, Hkv, D)."""
+    if interpret is None:
+        interpret = default_interpret()
     H, Hkv = q.shape[2], k.shape[2]
     if Hkv != H:
         rep = H // Hkv

@@ -9,11 +9,13 @@ Two entry points:
     (G = H/Hkv rows) against one un-repeated K/V stream, so no
     ``jnp.repeat``-materialised copies ever hit HBM.
   * :func:`paged_flash_decode_kernel` — block-paged KV.  K/V live in a
-    shared page pool ``(P, page, Hkv, D)``; the per-sequence page table is a
+    shared page pool ``(P, Hkv, page, D)``; the per-sequence page table is a
     scalar-prefetch operand so the BlockSpec index_map gathers the right
     physical page per kv block *inside* the kernel (one kv block == one
-    page).  Optional sliding-window masking supports paged SWA caches,
-    which keep all positions and mask instead of ring-rotating.
+    page of one KV head, a ``(page, D)`` tile — the TPU lowering needs a
+    block's last two dims to be whole array dims or (8, 128)-aligned).
+    Optional sliding-window masking supports paged SWA caches, which keep
+    all positions and mask instead of ring-rotating.
 
 Valid-length masking supports ragged KV prefixes (continuous batching).
 """
@@ -137,7 +139,7 @@ def _paged_decode_kernel(ptab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _body():
         q = q_ref[0].astype(jnp.float32)                 # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # (page, D)
+        k = k_ref[0, 0].astype(jnp.float32)              # (page, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -150,7 +152,7 @@ def _paged_decode_kernel(ptab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = (acc_scr[...] * alpha
                         + jax.lax.dot_general(
-                            p, v_ref[0, :, 0, :].astype(jnp.float32),
+                            p, v_ref[0, 0].astype(jnp.float32),
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
         m_scr[...] = m_new
@@ -167,7 +169,7 @@ def paged_flash_decode_kernel(q: jax.Array, kp: jax.Array, vp: jax.Array,
                               interpret: bool = False) -> jax.Array:
     """One-token decode attention over a block-paged KV pool.
 
-    q: (B, H, D); kp, vp: (P, page, Hkv, D) shared physical page pools;
+    q: (B, H, D); kp, vp: (P, Hkv, page, D) shared physical page pools;
     ptab: (B, n_ptab) int32 logical-block → physical-page map (0 = trash
     page for unmapped blocks); kv_len: (B,) valid tokens per sequence.
 
@@ -176,7 +178,7 @@ def paged_flash_decode_kernel(q: jax.Array, kp: jax.Array, vp: jax.Array,
     exactly the pages a sequence owns, never a contiguous copy.  Grid axis 0
     walks (batch × kv head); the q block is that head's whole GQA group.
     """
-    P, page, Hkv, D = kp.shape
+    P, Hkv, page, D = kp.shape
     B, H, _ = q.shape
     assert H % Hkv == 0, (H, Hkv)
     G = H // Hkv
@@ -190,12 +192,12 @@ def paged_flash_decode_kernel(q: jax.Array, kp: jax.Array, vp: jax.Array,
         grid=(B * Hkv, n_ptab),
         in_specs=[
             pl.BlockSpec((1, G, D), lambda i, ki, pt, kl: (i, 0, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda i, ki, pt, kl: (pt[i // Hkv, ki], 0,
-                                                i % Hkv, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda i, ki, pt, kl: (pt[i // Hkv, ki], 0,
-                                                i % Hkv, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda i, ki, pt, kl: (pt[i // Hkv, ki], i % Hkv,
+                                                0, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda i, ki, pt, kl: (pt[i // Hkv, ki], i % Hkv,
+                                                0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, D), lambda i, ki, pt, kl: (i, 0, 0)),
         scratch_shapes=[

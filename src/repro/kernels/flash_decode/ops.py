@@ -1,38 +1,26 @@
 """jit'd public wrappers for flash decode (GQA-aware, contiguous + paged).
 
-``interpret`` defaults from the backend (env override
-``REPRO_PALLAS_INTERPRET=0|1``): the Pallas interpreter is a debugging aid,
-not a serving path — on TPU the compiled kernel runs, elsewhere interpret
-mode keeps the kernels testable.  GQA grouping lives inside the kernels;
-nothing here materialises repeated K/V.
+``interpret`` defaults from the backend (:func:`repro.kernels.default_interpret`):
+the Pallas interpreter is a debugging aid, not a serving path — on TPU the
+compiled kernel runs, elsewhere interpret mode keeps the kernels testable.
+GQA grouping lives inside the kernels; nothing here materialises repeated
+K/V.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.4.35 re-exports shard_map at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+from repro.kernels import default_interpret
 from repro.kernels.flash_decode.kernel import (flash_decode_kernel,
                                                paged_flash_decode_kernel)
 from repro.kernels.flash_decode.ref import (flash_decode_ref,
                                             paged_flash_decode_ref)
-
-
-def default_interpret() -> bool:
-    """Interpret Pallas kernels?  Env wins, else: compiled on TPU only."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.lower() not in ("0", "false", "no")
-    return jax.default_backend() != "tpu"
 
 
 def _repeat_kv(q, k, v):
@@ -63,12 +51,13 @@ def paged_flash_decode_head_slice(q: jax.Array, kp: jax.Array, vp: jax.Array,
                                   ptab: jax.Array, kv_len: jax.Array,
                                   kv_head_offset, total_kv_heads: int,
                                   window: Optional[int] = None,
-                                  interpret: bool = True) -> jax.Array:
+                                  interpret: Optional[bool] = None
+                                  ) -> jax.Array:
     """Fused paged decode over one contiguous KV-head slice — the single
     kernel wrapper shared by the unsharded path and each shard_map shard.
 
     ``q`` carries the FULL head set (B, H, D); ``kp``/``vp`` carry exactly
-    this slice's KV heads (P, page, Hkv_slice, D) — the whole pool on one
+    this slice's KV heads (P, Hkv_slice, page, D) — the whole pool on one
     device, or a shard's local pool slice under shard_map.
     ``kv_head_offset`` counts KV heads (may be traced, e.g. ``axis_index``
     inside shard_map) and selects the matching GQA q-head block
@@ -76,7 +65,7 @@ def paged_flash_decode_head_slice(q: jax.Array, kp: jax.Array, vp: jax.Array,
     slice-local.  Returns that block's outputs (B, G*Hkv_slice, D).
     """
     B, H, D = q.shape
-    hkv_slice = kp.shape[2]
+    hkv_slice = kp.shape[1]
     if total_kv_heads <= 0 or H % total_kv_heads != 0:
         raise ValueError(
             f"GQA grouping needs n_heads ({H}) divisible by total KV heads "
@@ -85,6 +74,8 @@ def paged_flash_decode_head_slice(q: jax.Array, kp: jax.Array, vp: jax.Array,
     G = H // total_kv_heads
     q_slice = jax.lax.dynamic_slice_in_dim(
         q, kv_head_offset * G, hkv_slice * G, axis=1)
+    if interpret is None:
+        interpret = default_interpret()
     return paged_flash_decode_kernel(q_slice, kp, vp,
                                      ptab.astype(jnp.int32),
                                      kv_len.astype(jnp.int32),
@@ -94,7 +85,7 @@ def paged_flash_decode_head_slice(q: jax.Array, kp: jax.Array, vp: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def _paged_flash_decode(q, kp, vp, ptab, kv_len, window, interpret):
     return paged_flash_decode_head_slice(q, kp, vp, ptab, kv_len, 0,
-                                         kp.shape[2], window=window,
+                                         kp.shape[1], window=window,
                                          interpret=interpret)
 
 
@@ -102,7 +93,7 @@ def paged_flash_decode(q: jax.Array, kp: jax.Array, vp: jax.Array,
                        ptab: jax.Array, kv_len: jax.Array,
                        window: Optional[int] = None,
                        interpret: Optional[bool] = None) -> jax.Array:
-    """Paged decode: q (B, H, D); kp/vp (P, page, Hkv, D); ptab (B, n_ptab)
+    """Paged decode: q (B, H, D); kp/vp (P, Hkv, page, D); ptab (B, n_ptab)
     logical-block → physical-page; kv_len (B,).  The page table is gathered
     inside the kernel via scalar prefetch."""
     if interpret is None:
@@ -130,7 +121,7 @@ def sharded_paged_flash_decode(q: jax.Array, kp: jax.Array, vp: jax.Array,
     if interpret is None:
         interpret = default_interpret()
     tp = mesh.shape[axis]
-    hkv = kp.shape[2]
+    hkv = kp.shape[1]
     if hkv % tp != 0:
         raise ValueError(
             f"n_kv_heads={hkv} not divisible by tp={tp} on axis {axis!r}; "
@@ -139,19 +130,19 @@ def sharded_paged_flash_decode(q: jax.Array, kp: jax.Array, vp: jax.Array,
     local = hkv // tp
 
     def local_decode(qf, kp_l, vp_l, pt, kl):
-        # qf (B, H, D) replicated; kp_l/vp_l (P, page, Hkv/tp, D) local
+        # qf (B, H, D) replicated; kp_l/vp_l (P, Hkv/tp, page, D) local
         off = jax.lax.axis_index(axis) * local
         return paged_flash_decode_head_slice(qf, kp_l, vp_l, pt, kl, off,
                                              hkv, window=window,
                                              interpret=interpret)
 
-    in_specs = (P(), P(None, None, axis, None), P(None, None, axis, None),
+    in_specs = (P(), P(None, axis, None, None), P(None, axis, None, None),
                 P(), P())
-    # check_rep=False: pallas_call has no replication rule; outputs
+    # check_vma=False: pallas_call has no replication rule; outputs
     # concatenate along the shard axis in head order (no psum)
-    return _shard_map(local_decode, mesh=mesh, in_specs=in_specs,
-                      out_specs=P(None, axis, None), check_rep=False)(
-                          q, kp, vp, ptab, kv_len)
+    return shard_map(local_decode, mesh=mesh, in_specs=in_specs,
+                     out_specs=P(None, axis, None), check_vma=False)(
+                         q, kp, vp, ptab, kv_len)
 
 
 def reference(q, k, v, kv_len):

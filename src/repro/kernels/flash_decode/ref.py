@@ -31,14 +31,14 @@ def paged_flash_decode_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
                            ptab: jax.Array, kv_len: jax.Array,
                            window: Optional[int] = None) -> jax.Array:
     """Oracle for paged decode: gather pages to a contiguous view, mask,
-    softmax.  q: (B, H, D); kp, vp: (P, page, Hkv, D); ptab: (B, n_ptab);
+    softmax.  q: (B, H, D); kp, vp: (P, Hkv, page, D); ptab: (B, n_ptab);
     kv_len: (B,).  GQA handled by head repetition (oracle only — the kernel
     never materialises the repeat)."""
-    P, page, Hkv, D = kp.shape
+    P, Hkv, page, D = kp.shape
     B, H, _ = q.shape
     S = ptab.shape[1] * page
-    k = kp[ptab].reshape(B, S, Hkv, D)                        # gather pages
-    v = vp[ptab].reshape(B, S, Hkv, D)
+    k = kp[ptab].swapaxes(2, 3).reshape(B, S, Hkv, D)         # gather pages
+    v = vp[ptab].swapaxes(2, 3).reshape(B, S, Hkv, D)
     if Hkv != H:
         rep = H // Hkv
         k = jnp.repeat(k, rep, axis=2)

@@ -9,13 +9,10 @@ import numpy as np
 
 
 def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the AxisType
-    enum) only exist on newer releases; older ones default to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated), the
+    sharding mode the repo's pjit rules are written for."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -17,19 +17,65 @@ reasons are printed.
 serves: each injected replica death is contained by the recovery domain
 (salvage live slots onto a survivor, requeue the rest with backoff) and the
 per-failure :class:`~repro.serving.pool.FailureReport` is printed.
+
+:func:`serve` — apply a plan, submit requests, drain — is the served path
+end to end; ``chip_smoke.py`` at the repository root drives the same
+function at full model width on a TPU.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Callable, List, Optional, Sequence
 
 import jax
 
 from repro.configs import get_config, list_archs
 from repro.core.plan import Plan, ReplicaGroup
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm
-from repro.serving.backend import JaxBackend
-from repro.serving.engine import Request
+from repro.serving.backend import JaxBackend, ReconfigReport
+from repro.serving.engine import Request, RequestState
+from repro.serving.pool import EnginePool
+
+
+@dataclass
+class ServeResult:
+    """What one :func:`serve` call did."""
+    report: ReconfigReport           # what applying the plan built/reused
+    done: List[RequestState]         # every request finished by the drain
+    wall_s: float                    # submission through drain
+
+    @property
+    def tokens(self) -> int:
+        return sum(len(d.generated) for d in self.done)
+
+
+def serve(backend: JaxBackend, plan: Plan, requests: Sequence[Request],
+          before_drain: Optional[Callable[[EnginePool], None]] = None
+          ) -> ServeResult:
+    """Apply ``plan``, submit ``requests`` to its (single) model, drain.
+
+    ``JaxBackend.apply_plan`` builds the plan's engines (or keeps warm ones
+    whose group is unchanged), ``EnginePool.submit`` routes each request to
+    the least-loaded replica, and ``run_until_drained`` steps every engine
+    until all finish.  ``before_drain(pool)`` runs between submission and
+    the drain (fault injection hooks in there)."""
+    models = {g.model for g in plan.groups}
+    if len(models) != 1:
+        raise ValueError(f"serve() routes to one model, plan has {models}")
+    (model,) = models
+    report = backend.apply_plan(plan, None)
+    t0 = time.monotonic()
+    for req in requests:
+        if not backend.pool.submit(model, req):
+            backend.pool.add_backlog(model, req)
+    if before_drain is not None:
+        before_drain(backend.pool)
+    done = backend.pool.run_until_drained()
+    return ServeResult(report, done, time.monotonic() - t0)
+
 
 def guarded_demo() -> None:
     """Evaluation ladder + canary/rollback on the deterministic shadow
@@ -117,6 +163,7 @@ def main() -> int:
         guarded_demo()
         return 0
 
+    use_compile_cache()
     cfg = get_config(args.arch).reduced()
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     backend = JaxBackend(cfg, params, max_seq_len=128, slots_cap=args.slots,
@@ -129,14 +176,11 @@ def main() -> int:
             name=args.priority).request_policy())
         print(f"request policy: {args.priority} admission order")
     model = cfg.name
-    plan = Plan((ReplicaGroup(model, "H100-80G", tp=1, batch=args.slots,
+    plan = Plan((ReplicaGroup(model, "TPU-v5e", tp=1, batch=args.slots,
                               count=args.replicas),))
-    report = backend.apply_plan(plan, None)
-    print(f"plan applied: built={len(report.built)} groups "
-          f"({args.replicas}×{args.slots}-slot engines) "
-          f"in {report.wall_s * 1e3:.1f}ms")
 
     inj = None
+    inject = None
     if args.faults is not None:
         from repro.core.policy import render_policy
         from repro.serving.faults import FaultInjector
@@ -151,31 +195,34 @@ def main() -> int:
               f"{[(ev.step, ev.kind) for ev in inj.schedule]} "
               f"(recovery policy: retry-migrate)")
 
-    t0 = time.monotonic()
-    for r in range(args.requests):
-        backend.pool.submit(model, Request(
-            rid=r, prompt=[1 + (r + j) % 9 for j in range(args.prompt_len)],
-            max_new_tokens=args.max_new, arrival_time=time.monotonic()))
-    if inj is not None:
-        pool = backend.pool
-        for i in range(3):
-            for eng in pool.engines:
-                eng.step(); eng.step()   # let kills land mid-decode
-            seen = len(pool.failure_log)
-            inj.step(pool, i)
-            for rep in pool.failure_log[seen:]:
-                print(f"  fault@step{i}: {rep.reason} model={rep.model} "
-                      f"salvaged={rep.salvaged} recomputed={rep.recomputed} "
-                      f"requeued={rep.requeued} shed={rep.shed} "
-                      f"leaked_pages={rep.leaked_pages}")
-            backend.apply_plan(plan, None)   # heal to the target count
-    done = backend.pool.run_until_drained()
-    dt = time.monotonic() - t0
-    toks = sum(len(d.generated) for d in done)
+        def inject(pool: EnginePool) -> None:
+            for i in range(3):
+                for eng in pool.engines:
+                    eng.step(); eng.step()   # let kills land mid-decode
+                seen = len(pool.failure_log)
+                inj.step(pool, i)
+                for rep in pool.failure_log[seen:]:
+                    print(f"  fault@step{i}: {rep.reason} model={rep.model} "
+                          f"salvaged={rep.salvaged} "
+                          f"recomputed={rep.recomputed} "
+                          f"requeued={rep.requeued} shed={rep.shed} "
+                          f"leaked_pages={rep.leaked_pages}")
+                backend.apply_plan(plan, None)   # heal to the target count
+
+    requests = [Request(rid=r, prompt=[1 + (r + j) % 9
+                                       for j in range(args.prompt_len)],
+                        max_new_tokens=args.max_new,
+                        arrival_time=time.monotonic())
+                for r in range(args.requests)]
+    res = serve(backend, plan, requests, before_drain=inject)
+    print(f"plan applied: built={len(res.report.built)} groups "
+          f"({args.replicas}×{args.slots}-slot engines) "
+          f"in {res.report.wall_s * 1e3:.1f}ms")
+    dt, toks = res.wall_s, res.tokens
     disp = backend.pool.total_dispatches
-    print(f"arch={args.arch} served {len(done)} requests, {toks} tokens "
+    print(f"arch={args.arch} served {len(res.done)} requests, {toks} tokens "
           f"in {dt:.2f}s ({toks / dt:.1f} tok/s, jitted dispatches={disp}, "
-          f"{disp / max(len(done), 1):.1f}/request)")
+          f"{disp / max(len(res.done), 1):.1f}/request)")
     if inj is not None:
         pool = backend.pool
         print(f"faults: kills={inj.kills} skipped={inj.skipped} "
@@ -199,7 +246,7 @@ def main() -> int:
                 max_new_tokens=args.max_new, arrival_time=time.monotonic()))
         for eng in backend.pool.engines:
             eng.step()
-        plan2 = Plan((ReplicaGroup(model, "H100-80G", tp=1,
+        plan2 = Plan((ReplicaGroup(model, "TPU-v5e", tp=1,
                                    batch=max(args.slots // 2, 1),
                                    count=args.replicas),))
         rep2 = backend.apply_plan(plan2, None)

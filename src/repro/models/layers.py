@@ -237,12 +237,15 @@ def paged_attention_fwd(p: Params, cfg: ModelConfig, x: jax.Array,
                         pos2: jax.Array, window: Optional[int],
                         kp: jax.Array, vp: jax.Array, ptab: jax.Array,
                         lens: jax.Array, widx: jax.Array,
-                        use_kernel: bool = False, interpret: bool = True
+                        use_kernel: bool = False,
+                        interpret: Optional[bool] = None
                         ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """GQA attention against a shared paged KV pool.
 
     x: (B, C, d) token chunk at absolute positions ``pos2`` (B, C);
-    kp/vp: (P, page, Hkv, D) physical page pools; ptab: (B, n_ptab) int32
+    kp/vp: (P, Hkv, page, D) physical page pools — head-major inside a
+    page, so the decode kernel's block per (page, KV head) is one aligned
+    ``(page, D)`` tile; ptab: (B, n_ptab) int32
     logical-block → physical-page map; lens: (B,) valid kv length *after*
     this chunk's writes; widx: (B, C) int32 flat pool row (page·page_size +
     offset) each token writes to — precomputed by the caller, with inactive
@@ -255,7 +258,7 @@ def paged_attention_fwd(p: Params, cfg: ModelConfig, x: jax.Array,
     """
     B, C, _ = x.shape
     H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    P, page = kp.shape[0], kp.shape[1]
+    page = kp.shape[2]
 
     q = x @ p["wq"].astype(x.dtype)
     if "bq" in p:
@@ -272,10 +275,9 @@ def paged_attention_fwd(p: Params, cfg: ModelConfig, x: jax.Array,
     k = apply_rope(k, pos2, cfg.rope_theta)
 
     flat = widx.reshape(-1)
-    new_kp = kp.reshape(P * page, Hkv, D).at[flat].set(
-        k.reshape(B * C, Hkv, D)).reshape(P, page, Hkv, D)
-    new_vp = vp.reshape(P * page, Hkv, D).at[flat].set(
-        v.reshape(B * C, Hkv, D)).reshape(P, page, Hkv, D)
+    wpage, woff = flat // page, flat % page
+    new_kp = kp.at[wpage, :, woff].set(k.reshape(B * C, Hkv, D))
+    new_vp = vp.at[wpage, :, woff].set(v.reshape(B * C, Hkv, D))
 
     if use_kernel and C == 1 and cfg.attn_logit_softcap is None:
         from repro.kernels.flash_decode import ops as fd_ops
@@ -293,8 +295,9 @@ def paged_attention_fwd(p: Params, cfg: ModelConfig, x: jax.Array,
                 interpret=interpret)[:, None]
     else:
         S = ptab.shape[1] * page
-        K = new_kp[ptab].reshape(B, S, Hkv, D)            # gather mapped pages
-        V = new_vp[ptab].reshape(B, S, Hkv, D)
+        # gather mapped pages: (B, n_ptab, Hkv, page, D) → (B, S, Hkv, D)
+        K = new_kp[ptab].swapaxes(2, 3).reshape(B, S, Hkv, D)
+        V = new_vp[ptab].swapaxes(2, 3).reshape(B, S, Hkv, D)
         kpos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
         mask = (_attn_mask(pos2, kpos, window)
                 & (kpos < lens[:, None])[:, None, None, :])

@@ -631,7 +631,9 @@ def paged_window(cfg: ModelConfig) -> Optional[int]:
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      dtype=jnp.bfloat16) -> Params:
     """Zero-filled paged pool pytree.  Physical page 0 is the trash page
-    (inactive-lane writes, unmapped page-table entries)."""
+    (inactive-lane writes, unmapped page-table entries).  K/V pools are
+    ``(L, P, Hkv, page, D)``: one page of one KV head is a contiguous
+    ``(page, D)`` tile, the block the fused decode kernel streams."""
     if not pageable(cfg):
         raise ValueError(f"family {cfg.family!r} is not pageable")
     if cfg.mla is not None:
@@ -639,17 +641,17 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
         return {"ckvp": jnp.zeros(
             (cfg.n_layers, n_pages, page_size,
              m.kv_lora_rank + m.qk_rope_head_dim), dtype)}
-    return {"kp": jnp.zeros((cfg.n_layers, n_pages, page_size,
-                             cfg.n_kv_heads, cfg.d_head), dtype),
-            "vp": jnp.zeros((cfg.n_layers, n_pages, page_size,
-                             cfg.n_kv_heads, cfg.d_head), dtype)}
+    return {"kp": jnp.zeros((cfg.n_layers, n_pages, cfg.n_kv_heads,
+                             page_size, cfg.d_head), dtype),
+            "vp": jnp.zeros((cfg.n_layers, n_pages, cfg.n_kv_heads,
+                             page_size, cfg.d_head), dtype)}
 
 
 def _paged_decoder_layer_fwd(p: Params, cfg: ModelConfig, x: jax.Array,
                              pos2: jax.Array, window: Optional[int], pool,
                              ptab: jax.Array, lens: jax.Array,
                              widx: jax.Array, use_kernel: bool,
-                             interpret: bool):
+                             interpret: Optional[bool]):
     """Pre-norm decoder layer against the paged pool. Returns (x, new_pool)."""
     h = rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
     if cfg.mla is not None:
@@ -669,7 +671,7 @@ def _paged_decoder_layer_fwd(p: Params, cfg: ModelConfig, x: jax.Array,
 def paged_step(params: Params, cfg: ModelConfig, cache: Params,
                tokens: jax.Array, pos2: jax.Array, ptab: jax.Array,
                active: jax.Array, *, page_size: int, use_kernel: bool = False,
-               interpret: bool = True) -> Tuple[jax.Array, Params]:
+               interpret: Optional[bool] = None) -> Tuple[jax.Array, Params]:
     """Cache-backed forward over a token chunk, paged pool edition.
 
     tokens/pos2: (B, C) int32; ptab: (B, n_ptab) int32 logical-block →
@@ -727,7 +729,8 @@ def paged_stage_step(params: Params, cfg: ModelConfig, cache: Params,
                      x: jax.Array, pos2: jax.Array, ptab: jax.Array,
                      active: jax.Array, *, page_size: int, first: bool,
                      last: bool, use_kernel: bool = False,
-                     interpret: bool = True) -> Tuple[jax.Array, Params]:
+                     interpret: Optional[bool] = None
+                     ) -> Tuple[jax.Array, Params]:
     """Paged forward over ONE pipeline stage's layer slice.
 
     ``x`` is int32 tokens (B, C) on the first stage and the previous stage's
@@ -800,8 +803,9 @@ def extract_paged_slot(cfg: ModelConfig, cache: Params, pages, position: int,
         L = ckv.shape[0]
         return {"ckv": ckv.reshape(L, S_src, -1),
                 "pos": np.broadcast_to(pos_row, (L, S_src)).copy()}
-    k = np.asarray(jax.device_get(cache["kp"][:, pages]))
-    v = np.asarray(jax.device_get(cache["vp"][:, pages]))
+    # (L, n, Hkv, page, D) → (L, n·page, Hkv, D)
+    k = np.asarray(jax.device_get(cache["kp"][:, pages])).swapaxes(2, 3)
+    v = np.asarray(jax.device_get(cache["vp"][:, pages])).swapaxes(2, 3)
     L = k.shape[0]
     return {"k": k.reshape(L, S_src, *k.shape[3:]),
             "v": v.reshape(L, S_src, *v.shape[3:]),
@@ -856,14 +860,19 @@ def install_paged_slot(cfg: ModelConfig, cache: Params, pages, state: Params,
         jsel = [j for j, pid in enumerate(pages) if pid != 0]
         pidx = np.asarray([pages[j] for j in jsel], np.int32)
         new_cache = dict(cache)
+        head_major = cfg.mla is None     # K/V pools hold (Hkv, page, D) pages
         for key, dst, src in zip(keys, dst_leaves, src_leaves):
+            row = ((dst.shape[2],) + tuple(dst.shape[4:]) if head_major
+                   else tuple(dst.shape[3:]))
             _require(src.shape[0] == L and src.shape[1] == S_src
-                     and tuple(src.shape[2:]) == tuple(dst.shape[3:]),
+                     and tuple(src.shape[2:]) == row,
                      f"state shape {tuple(src.shape)} incompatible with "
                      f"pool {tuple(dst.shape)}")
             buf = np.zeros((L, S_buf) + tuple(src.shape[2:]), dtype=dst.dtype)
             buf[:, sp[keep]] = src[:, keep]
             blocks = buf.reshape(L, n_blocks, page_size, *buf.shape[2:])
+            if head_major:
+                blocks = blocks.swapaxes(2, 3)
             new_cache[key] = dst.at[:, pidx].set(
                 jnp.asarray(blocks[:, jsel], dst.dtype))
         return new_cache

@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.policy import KVCachePolicy, RequestPolicy
-from repro.kernels.flash_decode.ops import default_interpret
+from repro.kernels import default_interpret
 from repro.models import lm
 from repro.serving import kvcache
 
@@ -342,6 +342,13 @@ class Engine(RequestSchedulingMixin):
                              f"KV cache (recurrent/xattn/paired state)")
         self.paged = bool(paged)
         self.page_size = page_size
+        # how decode attention runs: the fused paged Pallas kernel (compiled
+        # on TPU, interpreted elsewhere) or the jnp gather path
+        self.use_paged_kernel = False
+        self.interpret = default_interpret()
+        # trace-time flags (repro.models.flags) every jitted step runs
+        # under; sharded engines set their mesh switches here
+        self.trace_flags: Dict[str, object] = {}
         self.prefix_cache_enabled = self.paged and prefix_cache
         cache_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self.waiting: List[Request] = []
@@ -374,7 +381,8 @@ class Engine(RequestSchedulingMixin):
                 # the fused kernel runs compiled on TPU; in interpret mode
                 # the jnp gather path is the faster correctness path
                 use_paged_kernel = jax.default_backend() == "tpu"
-            interp = default_interpret()
+            self.use_paged_kernel = bool(use_paged_kernel)
+            interp = self.interpret
 
             def _pgexec(p, c, t, pos2, ptab, act):
                 logits, c2 = lm.paged_step(

@@ -66,7 +66,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.configs.base import ModelConfig
 from repro.core.plan import ReplicaGroup, default_stage_cuts, valid_stage_cuts
 from repro.distributed import sharding
-from repro.kernels.flash_decode.ops import default_interpret
 from repro.models import flags, lm
 from repro.serving import kvcache
 from repro.serving.engine import Engine
@@ -274,6 +273,7 @@ class ShardedEngine(Engine):
             scope["ep_shard"] = self._ep_flag
         if self.paged and self._paged_shard_flag is not None:
             scope["paged_shard"] = self._paged_shard_flag
+        self.trace_flags = scope
         if scope:
             if self.paged:
                 self._paged_exec = self._with_flags(self._paged_exec, scope)
@@ -409,6 +409,7 @@ class PipelinedEngine(Engine):
                 use_kernel = self._use_paged_kernel_kw
                 if use_kernel is None:
                     use_kernel = jax.default_backend() == "tpu"
+        self.use_paged_kernel = bool(use_kernel)
         stage_params, stage_caches = [], []
         for i in range(pp):
             lo, hi = self._bounds[i], self._bounds[i + 1]
@@ -484,8 +485,7 @@ class PipelinedEngine(Engine):
 
     def _make_paged_stage_fn(self, first: bool, last: bool,
                              use_kernel: bool):
-        cfg, page_size = self.cfg, self.page_size
-        interp = default_interpret()
+        cfg, page_size, interp = self.cfg, self.page_size, self.interpret
 
         def _fn(p, c, x, pos2, ptab, act):
             out, c2 = lm.paged_stage_step(

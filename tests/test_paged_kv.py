@@ -98,8 +98,8 @@ def test_paged_flash_decode_kernel_matches_ref(h, hkv, window):
     B, D, page, n_pages, pps = 3, 16, 8, 17, 6
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(k1, (B, h, D), jnp.float32)
-    kp = jax.random.normal(k2, (n_pages, page, hkv, D), jnp.float32)
-    vp = jax.random.normal(k3, (n_pages, page, hkv, D), jnp.float32)
+    kp = jax.random.normal(k2, (n_pages, hkv, page, D), jnp.float32)
+    vp = jax.random.normal(k3, (n_pages, hkv, page, D), jnp.float32)
     ptab = jax.random.randint(k4, (B, pps), 1, n_pages).astype(jnp.int32)
     kv_len = jnp.array([5, 23, 48], jnp.int32)
     out = paged_flash_decode_kernel(q, kp, vp, ptab, kv_len, window=window,
@@ -373,8 +373,8 @@ def test_head_slice_blocks_tile_the_full_kernel_output():
     n_pages = 1 + B * pps
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(7), 4)
     q = jax.random.normal(k1, (B, H, D), jnp.float32)
-    kp = jax.random.normal(k2, (n_pages, page, Hkv, D), jnp.float32)
-    vp = jax.random.normal(k3, (n_pages, page, Hkv, D), jnp.float32)
+    kp = jax.random.normal(k2, (n_pages, Hkv, page, D), jnp.float32)
+    vp = jax.random.normal(k3, (n_pages, Hkv, page, D), jnp.float32)
     ptab = jax.random.randint(k4, (B, pps), 1, n_pages).astype(jnp.int32)
     kv_len = jnp.array([9, 27], jnp.int32)
     full = ops.paged_flash_decode(q, kp, vp, ptab, kv_len)
@@ -382,8 +382,8 @@ def test_head_slice_blocks_tile_the_full_kernel_output():
     for tp in (2, 4):
         width = Hkv // tp
         parts = [ops.paged_flash_decode_head_slice(
-                     q, kp[:, :, i * width:(i + 1) * width],
-                     vp[:, :, i * width:(i + 1) * width],
+                     q, kp[:, i * width:(i + 1) * width],
+                     vp[:, i * width:(i + 1) * width],
                      ptab, kv_len, i * width, Hkv, interpret=True)
                  for i in range(tp)]
         assert all(p.shape == (B, G * width, D) for p in parts)
@@ -394,7 +394,7 @@ def test_head_slice_blocks_tile_the_full_kernel_output():
 def test_head_slice_rejects_indivisible_gqa_groups():
     from repro.kernels.flash_decode import ops
     q = jnp.zeros((1, 8, 16), jnp.float32)
-    kp = vp = jnp.zeros((3, 8, 3, 16), jnp.float32)
+    kp = vp = jnp.zeros((3, 3, 8, 16), jnp.float32)
     ptab = jnp.ones((1, 2), jnp.int32)
     kv_len = jnp.array([4], jnp.int32)
     with pytest.raises(ValueError, match="divisible"):

@@ -102,17 +102,17 @@ def test_paged_cache_pspecs_shards_heads_not_pages():
     cfg = get_config("qwen2-1.5b")
     pol = sh.ShardingPolicy(StubMesh({"data": 1, "model": 2}), mode="tp",
                             batch_axes=("data",))
-    cache = {"kp": _sds(2, 16, 64, 4, 16), "ckvp": _sds(2, 16, 64, 32)}
+    cache = {"kp": _sds(2, 16, 4, 64, 16), "ckvp": _sds(2, 16, 64, 32)}
     specs = sh.paged_cache_pspecs(cfg, pol, cache)
     # page axis must stay addressable from every shard → heads carry the
     # partition; MLA latent pool has no head axis and replicates
-    assert specs["kp"] == P(None, None, None, "model", None)
+    assert specs["kp"] == P(None, None, "model", None, None)
     assert specs["ckvp"] == P(None, None, None, None)
     # KV head count not divisible by tp → honest fallback to replication
     pol4 = sh.ShardingPolicy(TP4, mode="tp", batch_axes=("data",))
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", sh.ShardingFallback)
-        bad = sh.paged_cache_pspecs(cfg, pol4, {"kp": _sds(2, 16, 64, 2, 16)})
+        bad = sh.paged_cache_pspecs(cfg, pol4, {"kp": _sds(2, 16, 2, 64, 16)})
     assert bad["kp"] == P(None, None, None, None, None)
 
 
