@@ -110,6 +110,7 @@ def flash_decode_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(lens, qf, k, v)
     return out.reshape(B, H, D)
 
@@ -212,5 +213,6 @@ def paged_flash_decode_kernel(q: jax.Array, kp: jax.Array, vp: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
         interpret=interpret,
+        name="paged_flash_decode",
     )(ptab.astype(jnp.int32), kv_len.astype(jnp.int32), qf, kp, vp)
     return out.reshape(B, H, D)

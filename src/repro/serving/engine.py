@@ -40,6 +40,16 @@ EOS_DEFAULT = -1        # disabled unless the tokenizer defines one
 # candidate prefill chunk sizes (powers of two, greedy binary decomposition)
 _CHUNK_CANDIDATES = (256, 128, 64, 32, 16, 8, 4, 2, 1)
 
+# profiler spans of one engine step, always on (about a microsecond each with
+# the profiler off); a span's keyword args become its trace event's stats
+SPANS = ("step", "prefill", "decode", "decode.dispatch", "decode.sync",
+         "retire")
+
+
+def _span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """The span ``engine.<name>``; ``name`` is one of :data:`SPANS`."""
+    return jax.profiler.TraceAnnotation(f"engine.{name}", **args)
+
 
 class DrainStallError(RuntimeError):
     """``run_until_drained`` exhausted ``max_steps`` with work still in
@@ -139,6 +149,8 @@ class RequestState:
     prefill_dispatches: int = 0
     prior_generated: int = 0     # tokens produced before a preemption
                                  # (folded into the continuation's prompt)
+    admitted_at: Optional[float] = None   # time.monotonic() at admission
+    prefix_matched: int = 0      # prompt tokens mapped from the prefix index
 
 
 @dataclass
@@ -767,18 +779,21 @@ class Engine(RequestSchedulingMixin):
         Chunked mode decomposes the prompt into descending power-of-two
         chunks — O(log prompt_len) dispatches, exact semantics (no padding).
         """
-        st = RequestState(req, slot)
+        st = RequestState(req, slot, admitted_at=time.monotonic())
         self.active[slot] = st
         prompt = req.prompt or [0]
-        if self.paged:
-            last = self._paged_prefill(st, prompt)
-        elif not self.chunked_prefill:
-            last = 0
-            for i, tok in enumerate(prompt):
-                last = self._advance_slot(st, tok, wipe_slot=(i == 0))
-                st.prefill_dispatches += 1
-        else:
-            last = self._prefill_chunks(st, prompt)
+        with _span("prefill", rid=req.rid, prompt=len(prompt)) as span:
+            if self.paged:
+                last = self._paged_prefill(st, prompt)
+            elif not self.chunked_prefill:
+                last = 0
+                for i, tok in enumerate(prompt):
+                    last = self._advance_slot(st, tok, wipe_slot=(i == 0))
+                    st.prefill_dispatches += 1
+            else:
+                last = self._prefill_chunks(st, prompt)
+            span.set_metadata(matched=st.prefix_matched,
+                              tokens=len(prompt) - st.prefix_matched)
         st.generated.append(last)
         st.first_token_time = time.monotonic()
         if req.first_token_time is not None:
@@ -830,6 +845,7 @@ class Engine(RequestSchedulingMixin):
         matched = 0
         if self.prefix_cache_enabled:
             pages, matched = self.prefix_index.match(prompt, time.monotonic())
+            st.prefix_matched = matched
             for pid in pages:            # the request's own share of each page
                 self.page_pool.ref(pid)
         self._slot_pages[slot] = list(pages)
@@ -881,6 +897,10 @@ class Engine(RequestSchedulingMixin):
     # ------------------------------------------------------------------ #
     def step(self) -> int:
         """One engine iteration; returns number of tokens produced."""
+        with _span("step", step=self.steps):
+            return self._step_body()
+
+    def _step_body(self) -> int:
         t0 = time.monotonic()
         # 0. policy-gated preemption frees slots before admission
         self._maybe_preempt()
@@ -899,38 +919,47 @@ class Engine(RequestSchedulingMixin):
             return 0
 
         # 2. batched decode: assemble inputs host-side, ship once
-        tokens = np.zeros((self.n_slots, 1), np.int32)
-        positions = np.zeros((self.n_slots,), np.int32)
-        active = np.zeros((self.n_slots,), bool)
-        live: List[RequestState] = []
-        for slot, st in self.active.items():
-            tokens[slot, 0] = st.generated[-1]
-            positions[slot] = st.position
-            active[slot] = True
-            live.append(st)
-        if self.paged:
-            for st in live:              # map the block this write lands in
-                self._ensure_pages(st.slot, st.position + 1)
-            next_tok, self.cache = self._paged_exec(
-                self.params, self.cache, tokens, positions[:, None],
-                self._ptab, active)
-        else:
-            next_tok, self.cache = self._decode(self.params, self.cache,
-                                                tokens, positions, active,
-                                                np.zeros((self.n_slots,), bool))
-        self.dispatches += 1
-        next_np = np.asarray(next_tok)          # one device→host transfer
+        with _span("decode", step=self.steps, live=len(self.active)):
+            tokens = np.zeros((self.n_slots, 1), np.int32)
+            positions = np.zeros((self.n_slots,), np.int32)
+            active = np.zeros((self.n_slots,), bool)
+            live: List[RequestState] = []
+            for slot, st in self.active.items():
+                tokens[slot, 0] = st.generated[-1]
+                positions[slot] = st.position
+                active[slot] = True
+                live.append(st)
+            if self.paged:
+                for st in live:          # map the block this write lands in
+                    self._ensure_pages(st.slot, st.position + 1)
+            with _span("decode.dispatch"):
+                if self.paged:
+                    next_tok, self.cache = self._paged_exec(
+                        self.params, self.cache, tokens, positions[:, None],
+                        self._ptab, active)
+                else:
+                    next_tok, self.cache = self._decode(
+                        self.params, self.cache, tokens, positions, active,
+                        np.zeros((self.n_slots,), bool))
+            self.dispatches += 1
+            with _span("decode.sync"):
+                next_np = np.asarray(next_tok)  # one device→host transfer
+        # 3. append each slot's token and retire the finished
         produced = 0
-        for st in live:
-            tok = int(next_np[st.slot])
-            st.position += 1
-            st.generated.append(tok)
-            produced += 1
-            req = st.request
-            if (len(st.generated) >= req.max_new_tokens
-                    or tok == req.eos_id
-                    or st.position >= self.max_seq_len - 1):
-                self._retire(st.slot, st)
+        retired = 0
+        with _span("retire") as span:
+            for st in live:
+                tok = int(next_np[st.slot])
+                st.position += 1
+                st.generated.append(tok)
+                produced += 1
+                req = st.request
+                if (len(st.generated) >= req.max_new_tokens
+                        or tok == req.eos_id
+                        or st.position >= self.max_seq_len - 1):
+                    self._retire(st.slot, st)
+                    retired += 1
+            span.set_metadata(retired=retired)
         self.steps += 1
         self._record_step_time(time.monotonic() - t0)
         return produced
