@@ -144,6 +144,13 @@ def check_fused_compiled(eng) -> None:
     check(not eng.interpret, "engine runs Pallas kernels in interpret mode")
 
 
+def _weights(backend, eng) -> str:
+    """The served weights: bytes a step reads, and the leaves rounded to the
+    compute dtype by the backend and then by the engine (0: already done)."""
+    return (f"param_bytes={eng.param_bytes} "
+            f"params_cast={backend.params_cast}+{eng.params_cast}")
+
+
 def _peak_bytes(devices) -> str:
     parts = []
     for d in devices:
@@ -253,6 +260,7 @@ def one_chip_phase(seed: int) -> None:
           f"{time.monotonic() - t0:.2f}s")
     backend = JaxBackend(cfg, params, max_seq_len=MAX_SEQ, slots_cap=SLOTS,
                          max_replicas_per_group=1)
+    del params                          # the backend holds its served copy
     plan = Plan((ReplicaGroup(cfg.name, "TPU-v5e", tp=1, batch=SLOTS,
                               count=1),))
     waves = _requests(cfg.vocab_size, seed)
@@ -269,7 +277,7 @@ def one_chip_phase(seed: int) -> None:
                   f"max_seq_len={eng.max_seq_len} page={eng.page_size} "
                   f"pages={eng.page_pool.n_pages} "
                   f"fused_kernel={eng.use_paged_kernel} "
-                  f"interpret={eng.interpret}")
+                  f"interpret={eng.interpret} {_weights(backend, eng)}")
         print(f"[serve] wave {i}: {len(res.done)} requests "
               f"(prompts {[len(r.prompt) for r in wave]}) "
               f"{res.tokens} tokens in {res.wall_s:.2f}s; {clock}")
@@ -325,7 +333,8 @@ def _serve_tp_replicas(cfg, params, requests):
         check_fused_compiled(eng)
         ids = tuple(_device_ids(eng))
         print(f"[tp] replica {type(eng).__name__} tp={eng.tp} "
-              f"devices={list(ids)} fused_shard_map={eng.paged_kernel_fused}")
+              f"devices={list(ids)} fused_shard_map={eng.paged_kernel_fused} "
+              f"{_weights(backend, eng)}")
         logits[ids] = decode_logits(eng, requests[0].prompt, use_kernel=True,
                                     flags_scope=eng.trace_flags)
     a, b = logits
@@ -420,7 +429,8 @@ def four_chip_ep(seed: int) -> None:
     check(_device_ids(eng) == [d.id for d in devices],
           f"replica devices {_device_ids(eng)}")
     print(f"[ep] replica {type(eng).__name__} tp={eng.tp} ep=True "
-          f"devices={_device_ids(eng)} flags={sorted(eng.trace_flags)}")
+          f"devices={_device_ids(eng)} flags={sorted(eng.trace_flags)} "
+          f"{_weights(backend, eng)}")
     print(f"[ep] served {len(res.done)} requests, {res.tokens} tokens in "
           f"{res.wall_s:.2f}s; {clock}")
     prompt = wave[0].prompt

@@ -183,6 +183,44 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     return p
 
 
+# parameter leaves, by their key, that every forward reads only as
+# ``w.astype(x.dtype)`` with ``x`` in the compute dtype: matrices, biases and
+# the embedding (its rows and, tied, the head).  Norm scales and SSM
+# parameters are read in float32 and are not among them.
+_COMPUTE_DTYPE_LEAVES = frozenset({
+    "embed", "lm_head",
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv",
+    "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b",
+    "w_gate", "w_up", "w_down", "router"})
+
+
+def compute_dtype(cfg: ModelConfig):
+    return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+
+
+def serving_params(cfg: ModelConfig, params: Params) -> Params:
+    """``params`` as a served step reads them: each leaf of
+    ``_COMPUTE_DTYPE_LEAVES`` in the compute dtype, every other leaf as given.
+
+    Rounding once here gives the operands that each step would otherwise
+    round again from float32, so the forward's results are bit-identical
+    and no step program converts a weight.  A leaf already in its dtype is
+    passed through, not copied; shapes (``jax.ShapeDtypeStruct``) map to the
+    shapes of the cast tree.
+    """
+    dt = jnp.dtype(compute_dtype(cfg))
+
+    def one(path, x):
+        if (getattr(path[-1], "key", None) not in _COMPUTE_DTYPE_LEAVES
+                or x.dtype == dt):
+            return x
+        if isinstance(x, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(x.shape, dt, sharding=x.sharding)
+        return x.astype(dt)
+
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
 # --------------------------------------------------------------------------- #
 # remat helper
 # --------------------------------------------------------------------------- #
@@ -203,7 +241,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             frames: Optional[jax.Array] = None) -> jax.Array:
     """Full-sequence forward. tokens: (B, S) int32 → logits (B, S, V)."""
     B, S = tokens.shape
-    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    dtype = compute_dtype(cfg)
     x = params["embed"][tokens].astype(dtype)
     if cfg.local_global_every:          # gemma-style embedding normalizer
         x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
@@ -375,7 +413,7 @@ def step_with_cache(params: Params, cfg: ModelConfig, cache: Params,
                     ) -> Tuple[jax.Array, Params]:
     """Cache-backed forward over a token chunk. tokens/pos2: (B, C) int32."""
     B = tokens.shape[0]
-    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    dtype = compute_dtype(cfg)
     x = params["embed"][tokens].astype(dtype)
     if cfg.local_global_every:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
@@ -534,7 +572,7 @@ def stage_step(params: Params, cfg: ModelConfig, cache: Params,
     in order reproduces :func:`step_with_cache` exactly — same scans, same
     reduction order — which is what makes pp parity bit-exact in float32.
     """
-    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    dtype = compute_dtype(cfg)
     if first:
         x = params["embed"][x].astype(dtype)
         if cfg.local_global_every:
@@ -684,7 +722,7 @@ def paged_step(params: Params, cfg: ModelConfig, cache: Params,
     *after* this chunk lands.
     """
     B, C = tokens.shape
-    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    dtype = compute_dtype(cfg)
     x = params["embed"][tokens].astype(dtype)
     if cfg.local_global_every:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
@@ -744,7 +782,7 @@ def paged_stage_step(params: Params, cfg: ModelConfig, cache: Params,
     """
     B, C = pos2.shape
     if first:
-        dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+        dtype = compute_dtype(cfg)
         x = params["embed"][x].astype(dtype)
         if cfg.local_global_every:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)

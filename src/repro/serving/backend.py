@@ -33,7 +33,7 @@ from repro.core.policy import (KVCachePolicy, ReconfigPolicy, RecoveryPolicy,
                                RequestPolicy)
 from repro.core.simulator import Simulator
 from repro.models import lm
-from repro.serving.engine import Engine, Request
+from repro.serving.engine import Engine, Request, cast_params
 from repro.serving.pool import EnginePool, PoolDiff
 from repro.serving.sharded import SubmeshAllocator, engine_for_group
 
@@ -235,11 +235,14 @@ class JaxBackend:
     shard_replicas: bool = True
     pool: EnginePool = field(init=False)
     allocator: Optional[SubmeshAllocator] = field(init=False, default=None)
+    params_cast: int = field(init=False, default=0)
     _rid: int = 0
     _interval_no: int = 0
     _shed_seen: int = 0
 
     def __post_init__(self):
+        # one served copy of the weights: every engine a plan builds shares it
+        self.params, self.params_cast = cast_params(self.cfg, self.params)
         if self.shard_replicas and len(jax.devices()) > 1:
             self.allocator = SubmeshAllocator()
         self.pool = EnginePool(self._make_engine,

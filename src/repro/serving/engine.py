@@ -51,6 +51,13 @@ def _span(name: str, **args) -> jax.profiler.TraceAnnotation:
     return jax.profiler.TraceAnnotation(f"engine.{name}", **args)
 
 
+def cast_params(cfg: ModelConfig, params) -> Tuple[object, int]:
+    """``lm.serving_params`` of ``params``, and how many leaves it cast."""
+    served = lm.serving_params(cfg, params)
+    return served, sum(a is not b for a, b in zip(jax.tree.leaves(params),
+                                                  jax.tree.leaves(served)))
+
+
 class DrainStallError(RuntimeError):
     """``run_until_drained`` exhausted ``max_steps`` with work still in
     flight — a stall (e.g. a retry loop that never converges, or a backoff
@@ -329,7 +336,9 @@ class Engine(RequestSchedulingMixin):
                  kv_cache_policy: Optional[KVCachePolicy] = None,
                  use_paged_kernel: Optional[bool] = None):
         self.cfg = cfg
-        self.params = params
+        # the step reads its weights in the compute dtype, rounded once here
+        # (not in every step); 0 leaves cast when handed a served tree
+        self.params, self.params_cast = cast_params(cfg, params)
         self.n_slots = n_slots
         self.max_seq_len = max_seq_len
         self.chunked_prefill = chunked_prefill
@@ -362,7 +371,7 @@ class Engine(RequestSchedulingMixin):
         # under; sharded engines set their mesh switches here
         self.trace_flags: Dict[str, object] = {}
         self.prefix_cache_enabled = self.paged and prefix_cache
-        cache_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+        cache_dtype = lm.compute_dtype(cfg)
         self.waiting: List[Request] = []
         self.active: Dict[int, RequestState] = {}       # slot -> state
         self.finished: List[RequestState] = []
@@ -501,6 +510,12 @@ class Engine(RequestSchedulingMixin):
     def load(self) -> int:
         """Outstanding work: queued + in-flight requests (pool routing key)."""
         return len(self.waiting) + len(self.active)
+
+    @property
+    def param_bytes(self) -> int:
+        """Bytes of the weights each step reads (all stages, all shards)."""
+        return sum(x.size * x.dtype.itemsize
+                   for x in jax.tree.leaves(self.params))
 
     # request-domain policy dispatch (request_ctx_for/_score/
     # _select_admissions/_maybe_preempt/migration_ctx_for) is inherited

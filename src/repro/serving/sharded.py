@@ -263,7 +263,7 @@ class ShardedEngine(Engine):
                         f"paged_kernel:{reason}", 3, cfg.n_kv_heads, "", tp))
         super().__init__(cfg, params, **kw)
         self.params = jax.device_put(
-            params, sharding._ns(mesh, self.decision.param_specs))
+            self.params, sharding._ns(mesh, self.decision.param_specs))
         spec_fn = (sharding.paged_cache_pspecs if self.paged
                    else sharding.cache_pspecs)
         self._cache_ns = sharding._ns(mesh, spec_fn(cfg, pol, self.cache))
@@ -372,7 +372,7 @@ class PipelinedEngine(Engine):
         # swaps the allocator/trie for their lockstep per-stage versions
         self._use_paged_kernel_kw = kw.get("use_paged_kernel")
         super().__init__(cfg, params, **kw)
-        self._build_stages(params)
+        self._build_stages(self.params)
 
     # -------------------------------------------------------------- #
     @property

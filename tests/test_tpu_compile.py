@@ -9,6 +9,7 @@ this file loads the TPU library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,11 +115,16 @@ def test_moe_gmm_compiles_at_mixtral_ep_shard_widths(one_chip):
 
 def test_full_width_qwen2_decode_step_fits_one_chip(one_chip):
     """The whole served decode step — 28 layers at published widths with
-    the fused kernel — compiles for one v5e and fits its 16 GB."""
+    the fused kernel — compiles for one v5e and fits its 16 GB, on the
+    float32 tree ``init_params`` draws and on the tree an engine serves
+    (``serving_params``: matrices in bfloat16), whose arguments are about
+    half the float32 tree's."""
     from repro.models import lm
     cfg = get_config("qwen2-1.5b")
     params = jax.eval_shape(lambda k: lm.init_params(cfg, k),
                             jax.random.PRNGKey(0))
+    served = jax.eval_shape(lambda k: lm.serving_params(
+        cfg, lm.init_params(cfg, k)), jax.random.PRNGKey(0))
     cache = jax.eval_shape(lambda: lm.init_paged_cache(
         cfg, _n_pages(), PAGE, dtype=jnp.bfloat16))
 
@@ -132,13 +138,23 @@ def test_full_width_qwen2_decode_step_fits_one_chip(one_chip):
     def step(p, c, t, pos2, ptab, act):
         return lm.paged_step(p, cfg, c, t, pos2, ptab, act, page_size=PAGE,
                              use_kernel=True, interpret=False)
-    compiled = jax.jit(step).lower(
-        place(params), place(cache), i32((SLOTS, 1)), i32((SLOTS, 1)),
-        i32((SLOTS, MAX_SEQ // PAGE)),
-        jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one_chip)
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert total < HBM_BYTES, f"decode step needs {total / 1e9:.2f} GB"
+    args, converts = {}, {}
+    for name, tree in (("float32", params), ("served", served)):
+        compiled = jax.jit(step).lower(
+            place(tree), place(cache), i32((SLOTS, 1)), i32((SLOTS, 1)),
+            i32((SLOTS, MAX_SEQ // PAGE)),
+            jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one_chip)
+        ).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        assert total < HBM_BYTES, (f"{name} decode step needs "
+                                   f"{total / 1e9:.2f} GB")
+        args[name] = mem.argument_size_in_bytes
+        # an op that rounds a stacked (per-layer) weight inside the step
+        converts[name] = len(re.findall(
+            rf"%convert[\w.]* = bf16\[{cfg.n_layers},", compiled.as_text()))
+    assert converts["float32"] > 0 and converts["served"] == 0, converts
+    assert args["served"] <= 4.3e9, f"served args {args['served'] / 1e9:.2f} GB"
+    assert args["served"] < 0.55 * args["float32"], args
