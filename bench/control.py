@@ -20,7 +20,6 @@ PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
-import os  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -34,11 +33,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     from bench import harness, spec
     cell = spec.load_cell(args.workload, ROOT)
-    import jax
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    harness.use_compile_cache(ROOT)
     for seed in (int(s) for s in args.seeds.split(",")):
         try:
             r = harness.run(cell, seed, args.seconds, False, time.monotonic(),

@@ -21,7 +21,14 @@ span go to the profiler when the run is traced.
 After the window the program's device state is freed and a sample of the
 finished requests is run through the float32 reference: the number
 compared is the widest gap by which a served token's logit lies below the
-reference's best logit at its position.
+reference's best logit at its position.  For a mixture of experts whose
+limits give a ``router_tie_margin`` δ, a position where the reference's
+k-th and (k+1)-th router logits lie within δ of each other is a tie, which
+the program's rounding may resolve either way: ties are left out of the
+widest gap and counted in ``router_tie_share`` (:func:`judge`).
+
+A cell that serves across chips draws its weights, and runs the reference,
+split over those chips (:func:`bench.weights.placement`).
 """
 from __future__ import annotations
 
@@ -30,6 +37,7 @@ import gc
 import glob
 import json
 import math
+import os
 import shutil
 import sys
 import tempfile
@@ -56,6 +64,23 @@ def log(msg: str) -> None:
 # --------------------------------------------------------------------------- #
 # set-up
 # --------------------------------------------------------------------------- #
+def use_compile_cache(root: Path = spec.ROOT) -> str:
+    """The benchmark's one rule for JAX's persistent compilation cache, called
+    before anything compiles: on, at the fixed ``<root>/.jax_cache``, for
+    every program whatever its compile time, so that only a checkout's first
+    run of a cell compiles.  A cell across chips keeps it too: a tensor- and
+    expert-parallel Mixtral engine on four v5e chips loaded all its programs
+    from it and served correctly (jax 0.9.0), where sharded executables from
+    the cache had once halted the chip.  Returns the directory."""
+    import jax
+    # the program keeps its cache where this variable says
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(root / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir",
+                      os.environ["JAX_COMPILATION_CACHE_DIR"])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return os.environ["JAX_COMPILATION_CACHE_DIR"]
+
+
 def devices_for(chips: int, require_tpu: bool):
     import jax
     devs = jax.devices()
@@ -102,6 +127,13 @@ def replica_group(cell: spec.Cell, model: str):
     return group
 
 
+def chip_devices(n: int) -> List:
+    """The first ``n`` devices in id order, the order in which the pool's
+    allocator carves them."""
+    import jax
+    return sorted(jax.devices(), key=lambda d: d.id)[:n]
+
+
 def build(cell: spec.Cell, seed: int):
     """The program's served path for the cell, with the seed's weights."""
     import jax
@@ -116,13 +148,18 @@ def build(cell: spec.Cell, seed: int):
     a = arch(cell.config)
     check_config(cfg, a)
     eng_cfg = cell.config["engine"]
-    params = weights.make_program_params(a, seed)
+    group = replica_group(cell, cfg.name)
+    # drawn across the chips of the first replica (a replica of one chip
+    # takes the one-device draw)
+    params = weights.make_program_params(a, seed, chip_devices(group.tp))
     weights.check_layout(params, jax.eval_shape(
         lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
-    group = replica_group(cell, cfg.name)
     backend = JaxBackend(cfg, params, max_seq_len=int(eng_cfg["max_seq_len"]),
                          slots_cap=int(eng_cfg["slots"]),
                          max_replicas_per_group=group.count)
+    # the backend holds the served copy, in the compute dtype; the float32
+    # tree goes before the engines and their page pools are built
+    del params
     backend.apply_plan(Plan((group,)), None)
     engines = backend.pool.engines
     if len(engines) != group.count:
@@ -396,16 +433,17 @@ def served_sequences(states: List) -> List[tuple]:
 
 
 def reference_gaps(w, a, seqs: List[tuple], mode: str = "f32",
-                   targets_from: Optional[str] = None) -> List[np.ndarray]:
+                   targets_from: Optional[str] = None) -> tuple:
     """For each (prompt, served) sequence, the float32 reference's gap at
     every served token: its best logit less the logit of the served token
     (or, with ``targets_from``, of the token that the ``targets_from``
-    precision puts first there)."""
+    precision puts first there); and its router margin there (+inf for a
+    dense model).  Returns the two lists."""
     import jax
     import jax.numpy as jnp
 
     from bench.reference.model import scores
-    out = []
+    out, margins = [], []
     with jax.default_matmul_precision("highest"):
         for prompt, served in seqs:
             seq = prompt + served
@@ -416,13 +454,15 @@ def reference_gaps(w, a, seqs: List[tuple], mode: str = "f32",
             tgt[:len(seq) - 1] = seq[1:]
             lo, hi = len(prompt) - 1, len(seq) - 1
             if targets_from is not None:
-                _, _, arg = scores(w, jnp.asarray(tok), jnp.asarray(tgt),
-                                   a=a, mode=targets_from)
+                _, _, arg, _ = scores(w, jnp.asarray(tok), jnp.asarray(tgt),
+                                      a=a, mode=targets_from)
                 tgt[lo:hi] = np.asarray(arg)[lo:hi]
-            best, got, _ = scores(w, jnp.asarray(tok), jnp.asarray(tgt),
-                                  a=a, mode=mode)
+            best, got, _, margin = scores(w, jnp.asarray(tok),
+                                          jnp.asarray(tgt), a=a, mode=mode)
             out.append(np.asarray(best - got)[lo:hi])
-    return out
+            margins.append(np.full(hi - lo, np.inf, np.float32)
+                           if margin is None else np.asarray(margin)[lo:hi])
+    return out, margins
 
 
 def free_device_state() -> None:
@@ -434,19 +474,42 @@ def free_device_state() -> None:
     gc.collect()
 
 
-def judge(gaps: List[np.ndarray], limits: dict) -> Dict[str, dict]:
+def ties(margins: Optional[List[np.ndarray]], limits: dict
+         ) -> Optional[np.ndarray]:
+    """Which served positions are router ties: the reference's router margin
+    there is below the limits' ``router_tie_margin``.  None without it."""
+    if "router_tie_margin" not in limits:
+        return None
+    m = np.concatenate(margins) if margins else np.zeros(0)
+    return m < float(limits["router_tie_margin"])
+
+
+def judge(gaps: List[np.ndarray], limits: dict,
+          margins: Optional[List[np.ndarray]] = None) -> Dict[str, dict]:
     """The numbers compared, each beside its limit: how many served tokens
     were checked (at least ``min_tokens_checked``) and the widest gap
-    (at most ``max_logit_gap``)."""
+    (at most ``max_logit_gap``).  Where the limits give a
+    ``router_tie_margin``, the tokens at router ties are left out of both,
+    and where they give a ``max_router_tie_share``, the share of ties among
+    the served tokens is held to it (``router_tie_share``)."""
     g = np.concatenate(gaps) if gaps else np.zeros(0)
+    tie = ties(margins, limits)
+    if tie is not None:
+        g = g[~tie]
     served = int(len(g))
     widest = float(g.max()) if served else math.inf
-    return {"served_tokens_checked": {
-                "value": served, "limit": int(limits["min_tokens_checked"]),
-                "ok": served >= int(limits["min_tokens_checked"])},
-            "max_logit_gap": {
-                "value": widest, "limit": float(limits["max_logit_gap"]),
-                "ok": widest <= float(limits["max_logit_gap"])}}
+    out = {"served_tokens_checked": {
+               "value": served, "limit": int(limits["min_tokens_checked"]),
+               "ok": served >= int(limits["min_tokens_checked"])},
+           "max_logit_gap": {
+               "value": widest, "limit": float(limits["max_logit_gap"]),
+               "ok": widest <= float(limits["max_logit_gap"])}}
+    if tie is not None and "max_router_tie_share" in limits:
+        share = float(tie.mean()) if len(tie) else 1.0
+        out["router_tie_share"] = {
+            "value": share, "limit": float(limits["max_router_tie_share"]),
+            "ok": share <= float(limits["max_router_tie_share"])}
+    return out
 
 
 # --------------------------------------------------------------------------- #
@@ -468,13 +531,26 @@ def reduce_trace(trace_dir: str):
     return trace_reduce.reduce_file(files[0])
 
 
-def gap_readings(gaps: List[np.ndarray]) -> dict:
+def gap_readings(gaps: List[np.ndarray], margins: List[np.ndarray],
+                 limits: dict) -> dict:
+    """The gaps' spread and the five widest, each with its router margin
+    (None for a dense model); with a ``router_tie_margin`` in ``limits``, also
+    the ties and the widest gap at the other (decisive) positions."""
     g = np.concatenate(gaps) if gaps else np.zeros(0)
     if not len(g):
         return {"tokens": 0}
-    return {"tokens": int(len(g)), "max": float(g.max()),
-            "p99": float(np.quantile(g, 0.99)), "mean": float(g.mean()),
-            "nonzero": int((g > 0).sum())}
+    m = np.concatenate(margins)
+    out = {"tokens": int(len(g)), "max": float(g.max()),
+           "p99": float(np.quantile(g, 0.99)), "mean": float(g.mean()),
+           "nonzero": int((g > 0).sum()),
+           "widest": [[float(g[i]), float(m[i]) if np.isfinite(m[i])
+                       else None] for i in np.argsort(-g)[:5]]}
+    tie = ties(margins, limits)
+    if tie is not None:
+        out["ties"] = int(tie.sum())
+        out["decisive_max"] = (float(g[~tie].max()) if (~tie).any()
+                               else None)
+    return out
 
 
 def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
@@ -568,17 +644,17 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     free_device_state()
     from bench import weights
     t_ref = time.monotonic()
-    w = weights.make(a, seed)
-    gaps = reference_gaps(w, a, seqs)
-    readings = {"program": gap_readings(gaps)}
+    w = weights.make(a, seed, chip_devices(cell.chips))
+    gaps, margins = reference_gaps(w, a, seqs)
+    readings = {"program": gap_readings(gaps, margins, lim)}
     control_checks = None
     if control:
-        ctrl_gaps = reference_gaps(w, a, seqs, targets_from="fp8")
-        readings["control"] = gap_readings(ctrl_gaps)
-        control_checks = judge(ctrl_gaps, lim)
+        ctrl_gaps, _ = reference_gaps(w, a, seqs, targets_from="fp8")
+        readings["control"] = gap_readings(ctrl_gaps, margins, lim)
+        control_checks = judge(ctrl_gaps, lim, margins)
     del w
     free_device_state()
-    checks.update(judge(gaps, lim))
+    checks.update(judge(gaps, lim, margins))
     log(f"[check] program gaps {json.dumps(readings['program'])}")
     log(f"[check] reference over {len(seqs)} requests "
         f"({sum(len(s[1]) for s in seqs)} served tokens) in "

@@ -9,7 +9,10 @@ architecture is read from the configuration's Hugging Face keys.
 
 ``scores`` runs one sequence, layer by layer, and reduces the logits in
 row blocks to what a comparison needs: each position's best logit, the
-logit of a given target token, and the argmax.  In ``"f32"`` mode every
+logit of a given target token, and the argmax; for a mixture of experts
+also each position's router margin, the smallest over the layers of the
+gap between the k-th and the (k+1)-th largest router logit, which says how
+near the choice of experts lies to a tie.  In ``"f32"`` mode every
 product is float32 at ``Precision.HIGHEST``.  ``"fp8"`` mode is the
 lower-precision control: weights rounded to float8_e4m3fn with one scale per
 output channel, activations and the residual stream in bfloat16.
@@ -139,9 +142,16 @@ def _swiglu(x, wg, wu, wd, mode):
 def _moe(a: Arch, lw, h, mode):
     """Top-k of E experts: softmax over the router's logits, the k largest
     renormalised to sum to one; each expert's SwiGLU output weighted by its
-    gate, zero for the experts not chosen."""
+    gate, zero for the experts not chosen.  Returns the output and, at each
+    position, the k-th largest router logit less the (k+1)-th (+inf where
+    every expert is chosen)."""
     logits = jnp.matmul(h, _w(lw["router"], mode), precision=HIGHEST,
                         preferred_element_type=jnp.float32)
+    if a.top_k < a.experts:
+        kth = jax.lax.top_k(logits, a.top_k + 1)[0]
+        margin = kth[:, -2] - kth[:, -1]
+    else:
+        margin = jnp.full(logits.shape[:1], jnp.inf, jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, a.top_k)
     top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
@@ -154,35 +164,45 @@ def _moe(a: Arch, lw, h, mode):
 
     out, _ = jax.lax.scan(one, jnp.zeros(h.shape, jnp.float32),
                           jnp.arange(a.experts))
-    return out.astype(_act(mode))
+    return out.astype(_act(mode)), margin
 
 
 def _layer(a: Arch, mode: str, x, lw, pos):
+    """One layer; returns the new residual stream and the layer's router
+    margin (None for a dense layer)."""
     h = _rms(x, lw["attn_norm"], a.eps, mode)
     x = x + _attention(a, lw, h, pos, mode)
     h = _rms(x, lw["mlp_norm"], a.eps, mode)
     if a.experts:
-        return x + _moe(a, lw, h, mode)
-    return x + _swiglu(h, lw["w_gate"], lw["w_up"], lw["w_down"], mode)
+        y, margin = _moe(a, lw, h, mode)
+        return x + y, margin
+    return x + _swiglu(h, lw["w_gate"], lw["w_up"], lw["w_down"], mode), None
 
 
 # --------------------------------------------------------------------------- #
 # a whole sequence
 # --------------------------------------------------------------------------- #
+def _forward(w: Dict, tokens: jax.Array, a: Arch, mode: str):
+    """The final-normed hidden state and the router margin (the least over
+    the layers; None for a dense model) of one sequence."""
+    pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    x = w["embed"][tokens].astype(_act(mode))
+
+    def body(x, lw):
+        return _layer(a, mode, x, lw, pos)
+
+    x, margins = jax.lax.scan(body, x, w["layers"])
+    margin = None if margins is None else jnp.min(margins, axis=0)
+    return _rms(x, w["final_norm"], a.eps, mode), margin
+
+
 def hidden(w: Dict, tokens: jax.Array, a: Arch, mode: str = "f32"
            ) -> jax.Array:
     """The final-normed hidden state (S, d) of one sequence ``tokens`` (S,)
     under the weights ``w`` of :mod:`bench.weights` (``embed``,
     ``final_norm``, ``lm_head`` when untied, and ``layers``: each layer's
     tensors stacked on a leading axis)."""
-    pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    x = w["embed"][tokens].astype(_act(mode))
-
-    def body(x, lw):
-        return _layer(a, mode, x, lw, pos), None
-
-    x, _ = jax.lax.scan(body, x, w["layers"])
-    return _rms(x, w["final_norm"], a.eps, mode)
+    return _forward(w, tokens, a, mode)[0]
 
 
 def head(w: Dict, a: Arch, mode: str = "f32") -> jax.Array:
@@ -194,11 +214,12 @@ def head(w: Dict, a: Arch, mode: str = "f32") -> jax.Array:
 def scores(w: Dict, tokens: jax.Array, targets: jax.Array,
            *, a: Arch, mode: str = "f32"):
     """Run one sequence ``tokens`` (S,) through the model.  Returns, at each
-    position, the best logit, the logit of ``targets`` (S,) and the argmax.
-    S must be a multiple of ``ROW_BLOCK``; causal attention makes padding at
-    the end harmless to the positions before it."""
+    position, the best logit, the logit of ``targets`` (S,), the argmax and
+    the router margin (None for a dense model).  S must be a multiple of
+    ``ROW_BLOCK``; causal attention makes padding at the end harmless to the
+    positions before it."""
     S = tokens.shape[0]
-    x = hidden(w, tokens, a, mode)
+    x, margin = _forward(w, tokens, a, mode)
     hd = head(w, a, mode)
 
     def block(args):
@@ -212,4 +233,4 @@ def scores(w: Dict, tokens: jax.Array, targets: jax.Array,
     nb = S // ROW_BLOCK
     best, tgt, arg = jax.lax.map(
         block, (x.reshape(nb, ROW_BLOCK, -1), targets.reshape(nb, ROW_BLOCK)))
-    return best.reshape(S), tgt.reshape(S), arg.reshape(S)
+    return best.reshape(S), tgt.reshape(S), arg.reshape(S), margin

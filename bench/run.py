@@ -11,20 +11,18 @@ result, one JSON object; the numbers the correctness check compared are the
 last lines of standard error.  Exits non-zero and prints no result when JAX
 finds no TPU or fewer chips than the cell asks for, or when the system
 under test (``src/repro``) is not there.  JAX's persistent compilation cache
-is kept in ``<root>/.jax_cache``.
+is kept in ``<root>/.jax_cache`` (``harness.use_compile_cache``).
 """
 import time
 
 PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
-import os  # noqa: E402
 import sys  # noqa: E402
 import traceback  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
-CACHE_DIR = ROOT / ".jax_cache"
 
 
 def main(argv=None) -> int:
@@ -39,19 +37,13 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
-    # the program keeps its compile cache where this variable says; the
-    # benchmark gives it a fixed directory inside the checkout
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
     from bench import harness, spec
     try:
         cell = spec.load_cell(args.workload, ROOT)
     except spec.SpecError as e:
         print(f"run.py: {e}", file=sys.stderr)
         return 2
-    import jax
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    from repro.launch.compile_cache import use_compile_cache
-    use_compile_cache()
+    harness.use_compile_cache(ROOT)
     try:
         result = harness.run(cell, args.seed, args.seconds, bool(args.trace),
                              PROCESS_START, root=ROOT)

@@ -24,7 +24,6 @@ import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
-import os  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -128,11 +127,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
-    import jax
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    from repro.launch.compile_cache import use_compile_cache
-    use_compile_cache()
+    from bench import harness
+    harness.use_compile_cache(ROOT)
     result = run(args.workload, args.seed, args.seconds)
     print(json.dumps(result), flush=True)
     return 0

@@ -16,7 +16,6 @@ PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
-import os  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -31,14 +30,13 @@ def main(argv=None) -> int:
     ap.add_argument("--rates", required=True)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     from bench import harness, spec, stats, traffic
     cell = spec.load_cell(args.workload, ROOT)
     if cell.traffic["loop"] != "open":
         print("sweep.py: the cell's mix is not an open loop", file=sys.stderr)
         return 2
+    harness.use_compile_cache(ROOT)
     import jax
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     try:
         harness.devices_for(cell.chips, require_tpu=True)
     except harness.NoAccelerator as e:

@@ -133,3 +133,31 @@ def test_every_replica_is_warmed_and_stepped(tmp_path):
     assert len(out["steps"]) == 2
     # more than each engine's warm-up: traffic reached both
     assert min(out["steps"]) > 2 * harness.WARMUP_NEW
+
+
+
+_CACHE_RULE = """
+import json, sys
+from pathlib import Path
+sys.path[:0] = sys.argv[2:4]
+import jax, jax.numpy as jnp
+from bench import harness
+d = Path(sys.argv[1])
+where = harness.use_compile_cache(d)
+jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+print(json.dumps({"where": where,
+                  "files": len(list((d / ".jax_cache").glob("*")))}))
+"""
+
+
+def test_compile_cache_is_kept_in_the_checkout(tmp_path):
+    """The cache is at ``<root>/.jax_cache``, and a compile of any length
+    lands there."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    p = subprocess.run([sys.executable, "-c", _CACHE_RULE, str(tmp_path),
+                        str(ROOT / "src"), str(ROOT)],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["where"] == str(tmp_path / ".jax_cache") and out["files"] > 0

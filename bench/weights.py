@@ -9,7 +9,11 @@ scaled by ``FFN_OUT_GAIN`` and attention's output projection by
 ``ATTN_OUT_GAIN``; norm weights are ``1 + U(±0.2)`` and QKV biases
 ``U(±0.5)``, so that a path that skips either shows.  Everything is drawn
 in one jitted call on the device, in float32, the type the program keeps
-its weights in.
+its weights in.  Where a cell serves across chips, the same call runs with
+its output split over them (:func:`placement`): a four-layer Mixtral is
+24 GB of float32, more than one chip holds.  JAX's threefry is
+partitionable (``jax_threefry_partitionable``, on by default), so the values
+are those of the one-device draw, bit for bit.
 
 Why the two gains: with every matrix at ``±1/sqrt(fan_in)``, greedy
 decoding of a random model falls within a few tokens onto one token that it
@@ -33,6 +37,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from bench.reference.model import Arch
 
@@ -101,8 +106,52 @@ _make_plain = jax.jit(_draw, static_argnums=0)
 _make_program = jax.jit(_program_draw, static_argnums=0)
 
 
-def make(a: Arch, seed: int) -> Dict:
-    """The plain weights of ``seed``, drawn on the default device."""
+# The axis each weight is split on when it is drawn across chips: the one a
+# tensor-parallel matmul keeps apart, so each chip's share is a whole block of
+# its product.  Output features of the q/k/v, gate and up projections (and the
+# q/k/v biases), input features of the attention output and down projections,
+# the vocabulary of the embedding and head.  Norm weights and the router, under
+# a megabyte each, are whole on every chip.
+SPLIT = {"embed": 0, "lm_head": 1, "wq": -1, "wk": -1, "wv": -1, "bq": -1,
+         "bk": -1, "bv": -1, "wo": -2, "w_gate": -1, "w_up": -1, "w_down": -2}
+
+
+def placement(tree, devices, whole_experts: bool):
+    """``NamedSharding`` of each leaf of ``tree`` (arrays or shapes, keyed by
+    the names of :func:`shapes` or of the program's tree) over a 1-D mesh of
+    ``devices``: split on its ``SPLIT`` axis where the device count divides
+    it, whole otherwise.  A stack of experts (L, E, ., .) is split on its
+    expert axis instead when ``whole_experts``: the program's expert-parallel
+    engine holds whole experts on each chip, so the served copy then stays
+    where it was drawn; the reference takes one expert at a time, so its
+    copy keeps the feed-forward axis split and each expert on every chip."""
+    n = len(devices)
+    mesh = Mesh(np.array(devices), ("chips",))
+
+    def one(path, x):
+        axis = SPLIT.get(getattr(path[-1], "key", None))
+        if axis is not None and whole_experts and len(x.shape) == 4:
+            axis = 1
+        spec = [None] * len(x.shape)
+        if axis is not None and x.shape[axis] % n == 0:
+            spec[axis] = "chips"
+        return NamedSharding(mesh, PartitionSpec(*spec))
+
+    return jax.tree_util.tree_map_with_path(one, tree)
+
+
+def _draw_across(fn, a: Arch, seed: int, devices, whole_experts: bool):
+    kd = key_data(seed)
+    out = placement(jax.eval_shape(lambda k: fn(a, k), kd), devices,
+                    whole_experts)
+    return jax.jit(fn, static_argnums=0, out_shardings=out)(a, kd)
+
+
+def make(a: Arch, seed: int, devices=None) -> Dict:
+    """The plain weights of ``seed``: drawn on the default device, or split
+    over ``devices`` where they are more than one."""
+    if devices is not None and len(devices) > 1:
+        return _draw_across(_draw, a, seed, devices, whole_experts=False)
     return _make_plain(a, key_data(seed))
 
 
@@ -124,9 +173,14 @@ def program_params(a: Arch, w: Dict) -> Dict:
     return p
 
 
-def make_program_params(a: Arch, seed: int) -> Dict:
+def make_program_params(a: Arch, seed: int, devices=None) -> Dict:
     """Draw the weights of ``seed`` and lay them out for the program, in one
-    jitted call: the plain copy never exists beside the program's."""
+    jitted call: the plain copy never exists beside the program's.  On the
+    default device, or split over ``devices`` where they are more than one,
+    with whole experts on each chip."""
+    if devices is not None and len(devices) > 1:
+        return _draw_across(_program_draw, a, seed, devices,
+                            whole_experts=True)
     return _make_program(a, key_data(seed))
 
 
